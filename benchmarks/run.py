@@ -7,6 +7,9 @@ payload the suite attached via ``common.emit_json`` — op mixes,
 throughputs, load factors). CI's bench-smoke job uploads these as
 artifacts, seeding the perf trajectory across commits.
 
+A suite that raises still prints its ``*_SUITE_ERROR`` row and the harness
+goes on to the next suite, but the process then exits non-zero.
+
     PYTHONPATH=src python -m benchmarks.run [--fast] [--only fig3,...]
                                             [--json-dir bench-json]
 """
@@ -34,6 +37,8 @@ from . import (
     throughput,
     tiering,
 )
+from repro.compile_cache import enable_compile_cache
+
 from .common import JSON_RECORDS, ROWS
 
 SUITES = {
@@ -81,7 +86,7 @@ def _write_json(json_dir: pathlib.Path, name: str, fast: bool,
     print(f"# wrote {path}", file=sys.stderr)
 
 
-def main() -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="reduced sizes (CI smoke)")
@@ -89,13 +94,15 @@ def main() -> None:
                     help="comma-separated suite names")
     ap.add_argument("--json-dir", default=None,
                     help="directory for machine-readable BENCH_<suite>.json")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     names = (args.only.split(",") if args.only else list(SUITES))
     json_dir = None
     if args.json_dir is not None:
         json_dir = pathlib.Path(args.json_dir)
         json_dir.mkdir(parents=True, exist_ok=True)
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    errored = []
     for name in names:
         t0 = time.time()
         row_start = len(ROWS)
@@ -104,6 +111,7 @@ def main() -> None:
             SUITES[name](args.fast)
         except Exception as e:  # noqa: BLE001 — keep the harness running
             error = f"{type(e).__name__}:{e}"
+            errored.append(name)
             print(f"{name}_SUITE_ERROR,0.0,{error}", file=sys.stderr)
             print(f"{name}_suite_error,0.0,{type(e).__name__}")
         elapsed = time.time() - t0
@@ -111,7 +119,11 @@ def main() -> None:
             _write_json(json_dir, name, args.fast, elapsed,
                         ROWS[row_start:], error)
         print(f"# {name} done in {elapsed:.0f}s", file=sys.stderr)
+    if errored:
+        print(f"# suites errored: {','.join(errored)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -9,8 +9,11 @@ cuckoo filter membership, with deletion demonstrating contamination removal
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CuckooConfig, CuckooFilter
 from repro.data.kmer import kmer_keys, synthetic_genome
+
+enable_compile_cache()
 
 K = 31
 N_BASES = 200_000

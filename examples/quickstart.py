@@ -7,7 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import amq
+from repro.compile_cache import enable_compile_cache
 from repro.core import CuckooConfig, keys_from_numpy
+
+enable_compile_cache()
 
 # 1. One registry, every filter family. Pick a backend by name and size it
 #    by capacity — paper defaults (16-bit fingerprints, 16-slot buckets,
@@ -76,12 +79,3 @@ print(f"offset policy: {flex.table_bytes / 1024:.0f} KiB vs XOR "
       f"{filt.table_bytes / 1024:.0f} KiB")
 exact = amq.make("cuckoo", config=flex)
 print(f"handle from config: {exact.name}, {exact.table_bytes / 1024:.0f} KiB")
-
-# 8. Pallas kernel path (TPU-target; interpret-mode on CPU): batch query
-#    against a VMEM-resident table — kernels consume the same config/state.
-from repro.kernels import cuckoo_query
-
-live = keys[10_000:14_096]  # still stored (first 10k were deleted above)
-hits = cuckoo_query(filt.config, filt.state, live)
-print(f"kernel query: {int(hits.sum())}/4096 hits (expect 4096)")
-assert int(hits.sum()) == 4096

@@ -28,7 +28,6 @@ import numpy as np
 
 from ..core import cuckoo_filter as CF
 from ..core import sharded_filter as SF
-from ..core.compat import shard_map as _shard_map
 from ..core.hashing import keys_to_numpy
 from ..filters import bcht as HT
 from ..filters import blocked_bloom as BB
@@ -676,9 +675,10 @@ def _sharded_fn(config: ShardedAMQConfig, op: str, local_batch: int,
     fn = SF._make_sharded_op(config.inner, op, local_batch,
                              dedup_within_batch=dedup)
     n_in = 5 if op == "apply_ops" else 4
-    mapped = _shard_map(fn, mesh=config.mesh,
-                        in_specs=(P(ax),) * n_in,
-                        out_specs=(P(ax), P(ax), P(ax), P(ax)))
+    mapped = jax.shard_map(fn, mesh=config.mesh,
+                           in_specs=(P(ax),) * n_in,
+                           out_specs=(P(ax), P(ax), P(ax), P(ax)),
+                           check_vma=False)
     return jax.jit(mapped)
 
 

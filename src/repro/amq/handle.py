@@ -1,11 +1,11 @@
 """FilterHandle: the one stateful object every consumer programs against.
 
 Wraps (adapter, config, state) with per-op cached jits. State buffers are
-donated to mutating ops on accelerator backends (the handle immediately
-replaces its state, so the old buffers are dead — donation lets XLA update
-the table in place, the batch analogue of the paper's in-place CAS writes);
-on CPU, where XLA does not support donation, the jits are built without it
-to avoid per-compile warnings.
+donated to mutating ops on every backend (the handle immediately replaces
+its state, so the old buffers are dead — donation lets XLA update the table
+in place, the batch analogue of the paper's in-place CAS writes). A caller
+that kept a reference to an old ``handle.state`` finds it deleted after the
+next mutating op, on the CPU exactly as on a TPU.
 """
 
 from __future__ import annotations
@@ -124,8 +124,7 @@ class FilterHandle:
             raw = functools.partial(getattr(self.adapter, op), self.config,
                                     **static)
             if self.adapter.jit:
-                donate = ((0,) if op != "query"
-                          and jax.default_backend() != "cpu" else ())
+                donate = (0,) if op != "query" else ()
                 raw = jax.jit(raw, donate_argnums=donate)
             self._jits[key] = raw
         return self._jits[key]

@@ -308,7 +308,7 @@ def _insert_rounds(
             evict_mode, cur_tag, jnp.where(foundA, tag1, tag2))
         d_widx, d_sw = L.slot_to_word(d_slot, lay)
         d_words = jnp.where(foundA[:, None], wordsA, wordsB)
-        d_word = jnp.take_along_axis(d_words, d_widx[:, None], axis=1)[:, 0]
+        d_word = L.pick(d_words, d_widx, 1)
         d_desired = L.replace_tag(d_word, d_sw, d_tag, lay.fp_bits)
         d_addr = L.word_addr(d_bucket, d_widx, lay)
 
@@ -335,7 +335,7 @@ def _insert_rounds(
                 cstart = (_prng(e_tag, rnd + 1) % _U32(b)).astype(jnp.int32)
                 cslots = (cstart[:, None]
                           + jnp.arange(n_cand, dtype=jnp.int32)) % b  # [n,c]
-                ctags = jnp.take_along_axis(e_tags, cslots, axis=1)   # [n,c]
+                ctags = L.pick(e_tags[:, None, :], cslots, 2)       # [n,c]
                 calt = pol.alt_bucket(e_bucket[:, None], ctags)       # [n,c]
                 cwords = gather_words(table, calt)                # [n,c,wpb]
                 cfree = L.unpack_words(cwords, lay.fp_bits) == 0  # [n,c,b]
@@ -345,26 +345,22 @@ def _insert_rounds(
                 has_viable = jnp.any(cfound, axis=1)
                 jstar = jnp.argmax(cfound, axis=1).astype(jnp.int32)
 
-                take = lambda a: jnp.take_along_axis(
-                    a, jstar[:, None], axis=1)[:, 0]
+                take = lambda a: L.pick(a, jstar, 1)
                 r_src_slot = take(cslots)
                 r_tag = take(ctags)
                 r_reloc = take(reloc_tag)
                 r_dst_bucket = take(calt)
                 r_dst_slot = take(cslot_dst)
-                r_dst_words = jnp.take_along_axis(
-                    cwords, jstar[:, None, None], axis=1)[:, 0]   # [n, wpb]
+                r_dst_words = L.pick(cwords, jstar[:, None], 1)  # [n, wpb]
 
                 dst_widx, dst_sw = L.slot_to_word(r_dst_slot, lay)
-                dst_word = jnp.take_along_axis(
-                    r_dst_words, dst_widx[:, None], axis=1)[:, 0]
+                dst_word = L.pick(r_dst_words, dst_widx, 1)
                 dst_desired = L.replace_tag(dst_word, dst_sw, r_reloc,
                                             lay.fp_bits)
                 dst_addr = L.word_addr(r_dst_bucket, dst_widx, lay)
 
                 src_widx, src_sw = L.slot_to_word(r_src_slot, lay)
-                src_word = jnp.take_along_axis(
-                    e_words, src_widx[:, None], axis=1)[:, 0]
+                src_word = L.pick(e_words, src_widx, 1)
                 src_desired = L.replace_tag(src_word, src_sw, e_tag,
                                             lay.fp_bits)
                 src_addr = L.word_addr(e_bucket, src_widx, lay)
@@ -391,7 +387,7 @@ def _insert_rounds(
 
             # DFS eviction action (Alg. 1 lines 10-21).
             v_widx, v_sw = L.slot_to_word(vic, lay)
-            v_word = jnp.take_along_axis(e_words, v_widx[:, None], axis=1)[:, 0]
+            v_word = L.pick(e_words, v_widx, 1)
             v_desired = L.replace_tag(v_word, v_sw, e_tag, lay.fp_bits)
             v_evicted = L.extract_tag(v_word, v_sw, lay.fp_bits)
             v_addr = L.word_addr(e_bucket, v_widx, lay)
@@ -544,7 +540,7 @@ def _insert_frontier(
         d_tag = jnp.where(found1, tag1, tag2)
         d_widx, d_sw = L.slot_to_word(d_slot, lay)
         d_words = jnp.where(found1[:, None], words1, words2)
-        d_word = jnp.take_along_axis(d_words, d_widx[:, None], axis=1)[:, 0]
+        d_word = L.pick(d_words, d_widx, 1)
         d_desired = L.replace_tag(d_word, d_sw, d_tag, lay.fp_bits)
         d_addr = L.word_addr(d_bucket, d_widx, lay)
 
@@ -596,8 +592,7 @@ def _insert_frontier(
                     pos_b.append(nxt.astype(jnp.int32))
                     pos_s.append(vic)
                     lv_vic.append(vic)
-                    vtag = jnp.take_along_axis(
-                        tgs, vic[:, :, None], axis=2)[:, :, 0]
+                    vtag = L.pick(tgs, vic, 2)
                     move = pol.on_relocate(vtag)
                     nxt = pol.alt_bucket(nxt, vtag)
 
@@ -617,15 +612,12 @@ def _insert_frontier(
                 depth_star = jnp.where(use_lv[d], d + 1, depth_star)
             depth_star = jnp.where(has_chain, depth_star, 0)
 
-            take1 = lambda a, j: jnp.take_along_axis(
-                a, j[:, None], axis=1)[:, 0]
-            take2 = lambda a, j: jnp.take_along_axis(
-                a, j[:, None, None], axis=1)[:, 0]
+            take1 = lambda a, j: L.pick(a, j, 1)
+            take2 = lambda a, j: L.pick(a, j[:, None], 1)
 
             # Column 0: the root slot receives the key's own tag.
             r_widx, r_sw = L.slot_to_word(jstar, lay)
-            r_word = jnp.take_along_axis(
-                e_words, r_widx[:, None], axis=1)[:, 0]
+            r_word = L.pick(e_words, r_widx, 1)
             r_addr = L.word_addr(e_bucket, r_widx, lay)
             addrs = [jnp.where(has_chain, r_addr, invalid)]
             sws, wtags, cwords = [r_sw], [e_tag], [r_word]
@@ -643,7 +635,7 @@ def _insert_frontier(
                 lane = jnp.where(depth_star == t, lane_free, lane_vic)
                 used = has_chain & (depth_star >= t)
                 widx, sw = L.slot_to_word(lane, lay)
-                word = jnp.take_along_axis(wds, widx[:, None], axis=1)[:, 0]
+                word = L.pick(wds, widx, 1)
                 addr = L.word_addr(bkt, widx, lay)
                 addrs.append(jnp.where(used, addr, invalid))
                 sws.append(sw)
@@ -770,7 +762,6 @@ def _insert_orient(
     lay = config.layout
     pol = config.placement
     n = keys.shape[0]
-    b = config.bucket_size
     nb = config.num_buckets
     sweeps = max(1, config.orient_sweeps)
 
@@ -785,33 +776,37 @@ def _insert_orient(
     i2s = i2.astype(jnp.int32)
     aliased = i1s == i2s  # XOR degenerate: both endpoints coincide
 
-    tags_flat = L.unpack_words(state.table, lay.fp_bits)     # per-slot view
-    occ = jnp.sum(tags_flat.reshape(nb, b) != 0, axis=1, dtype=jnp.int32)
-    free = jnp.int32(b) - occ                                # [nb]
+    # Free capacity of each edge's two endpoints, read from the packed
+    # words of just the buckets this batch touches (SWAR zero-lane count) —
+    # never a per-slot view of the whole table.
+    free1 = _bucket_free(config, state.table, i1s)
+    free2 = _bucket_free(config, state.table, i2s)
 
     # Edges whose candidate buckets are both already full can never be
     # placed by orientation (existing entries never move); dropping them
     # from the sweep keeps the feasibility exit reachable — they go
     # straight to the residue pass. Active edges start pointing at an
     # endpoint that actually has headroom.
-    active = pending & ((free[i1s] > 0) | (free[i2s] > 0))
-    orient0 = active & (free[i1s] == 0) & ~aliased
+    active = pending & ((free1 > 0) | (free2 > 0))
+    orient0 = active & (free1 == 0) & ~aliased
 
     def sweep_body(carry):
         orient, _, s = carry
         dest = jnp.where(orient, i2s, i1s)
         other = jnp.where(orient, i1s, i2s)
+        free_dest = jnp.where(orient, free2, free1)
+        free_other = jnp.where(orient, free1, free2)
         dkey = jnp.where(active, dest, nb)
-        indeg = jnp.zeros((nb + 1,), jnp.int32).at[dkey].add(1)[:nb]
-        done = ~jnp.any(indeg > free)
+        indeg = jnp.zeros((nb + 1,), jnp.int32).at[dkey].add(1)
+        done = ~jnp.any(active & (indeg[dest] > free_dest))
 
         # Flip priority within an over-full bucket: edges whose other
         # endpoint still has headroom net of its own inflow move first
         # (spare, bit 31), then edges whose other endpoint is at least
         # non-full (flippable, bit 30); ties break pseudo-randomly (salted
         # per sweep so repeated sweeps explore new orientations).
-        flippable = free[other] > 0
-        spare = (free[other] - indeg[other]) > 0
+        flippable = free_other > 0
+        spare = (free_other - indeg[other]) > 0
         r = _prng(base_tag, s) >> _U32(2)
         score = (r
                  | jnp.where(spare, _U32(0x80000000), _U32(0))
@@ -821,7 +816,7 @@ def _insert_orient(
         order = jnp.lexsort((score, sort_key))
         sd = sort_key[order]
         rank = L.segment_ranks(sd)
-        cap = free[jnp.minimum(sd, nb - 1)]
+        cap = free_dest[order]
         flip_s = (rank >= cap) & (sd < nb)
         flip = jnp.zeros((n,), bool).at[order].set(flip_s)
         # A flip into a full bucket is pointless; masking it makes "no
@@ -841,16 +836,15 @@ def _insert_orient(
     # the opposite bucket for the few keys an unconverged sweep left over.
     dest = jnp.where(orient, i2s, i1s)
     stored = pol.place_tag(base_tag, orient)
-    tags_flat, placed1 = _bulk_place_phase(
-        config, tags_flat, dest, stored, pending)
+    table, placed1 = _bulk_place_phase(
+        config, state.table, dest, stored, pending)
     pending = pending & ~placed1
     dest2 = jnp.where(orient, i1s, i2s)
     stored2 = pol.place_tag(base_tag, ~orient)
-    tags_flat, placed2 = _bulk_place_phase(
-        config, tags_flat, dest2, stored2, pending)
+    table, placed2 = _bulk_place_phase(
+        config, table, dest2, stored2, pending)
     pending = pending & ~placed2
 
-    table = L.pack_tags(tags_flat, lay.fp_bits)
     placed = placed1 | placed2
     count = state.count + jnp.sum(placed, dtype=jnp.int32)
 
@@ -934,21 +928,32 @@ def insert(
 # ---------------------------------------------------------------------------
 
 
-def _bulk_place_phase(config: CuckooConfig, tags_flat: jnp.ndarray,
+def _bucket_free(config: CuckooConfig, table: jnp.ndarray,
+                 bucket: jnp.ndarray) -> jnp.ndarray:
+    """Empty slots of each given bucket: int32[n], from its packed words."""
+    lay = config.layout
+    words = L.gather_bucket_words(table, bucket, lay)           # [n, wpb]
+    zeros = jax.lax.population_count(L.swar_zero_mask(words, lay.fp_bits))
+    return jnp.sum(zeros, axis=-1, dtype=jnp.int32)
+
+
+def _bulk_place_phase(config: CuckooConfig, table: jnp.ndarray,
                       bucket: jnp.ndarray, stored_tag: jnp.ndarray,
                       pend: jnp.ndarray):
-    """One whole-bucket placement round over the unpacked per-slot table.
+    """One whole-bucket placement round, committed as packed-word writes.
 
     Sorts the pending keys by destination bucket, ranks each key within its
-    bucket segment, and commits the rank-th free slot of every bucket in a
-    single conflict-free scatter (each key owns a distinct slot by
-    construction — no word-claim election needed).
+    bucket segment, and gives every key the rank-th free slot of its bucket
+    — read from the unpacked rows of only the buckets the batch touches.
+    Every key owns a distinct, currently empty lane by construction, so the
+    commit is one scatter-*add* of each tag shifted into its lane: keys that
+    share a word add disjoint bits, which makes the result independent of
+    the order in which the device applies duplicate word addresses.
 
-    Returns (tags_flat', placed: bool[n] in original batch order).
+    Returns (table', placed: bool[n] in original batch order).
     """
     lay = config.layout
     n = bucket.shape[0]
-    b = config.bucket_size
     nb = config.num_buckets
 
     # One sort per phase — the whole point: pending keys grouped by bucket,
@@ -959,16 +964,17 @@ def _bulk_place_phase(config: CuckooConfig, tags_flat: jnp.ndarray,
     rank = L.segment_ranks(sb)
 
     safe_b = jnp.minimum(sb, nb - 1)
-    btags = tags_flat.reshape(nb, b)[safe_b]                  # [n, b]
+    btags = L.bucket_tags(table, safe_b, lay)                   # [n, b]
     placed_s, slot_s = L.nth_free_slot(btags, rank)
     placed_s = placed_s & (sb < nb)
-    dest = safe_b * b + slot_s
-    tags_flat = tags_flat.at[
-        jnp.where(placed_s, dest, lay.num_slots)
-    ].set(stored_tag[order], mode="drop")
+    widx, sw = L.slot_to_word(slot_s, lay)
+    addr = jnp.where(placed_s, L.word_addr(safe_b, widx, lay), lay.num_words)
+    lane = ((stored_tag[order].astype(jnp.uint32) & _U32(lay.fp_mask))
+            << (sw.astype(jnp.uint32) * _U32(lay.fp_bits)))
+    table = table.at[addr].add(lane, mode="drop")
 
     placed = jnp.zeros((n,), bool).at[order].set(placed_s)
-    return tags_flat, placed
+    return table, placed
 
 
 def insert_bulk(
@@ -984,13 +990,15 @@ def insert_bulk(
     buckets per round (paper §4.6.3's sorted insertion, promoted from a
     rejected GPU ablation to the batch-synchronous fast path — DESIGN.md §6):
 
-    1. unpack the table to its per-slot view (a pure bit-shuffle);
-    2. phase 1: place up to ``bucket_size`` keys per *primary* bucket —
-       each key takes the rank-th free slot of its bucket segment;
-    3. phase 2: re-sort the overflow by *alternate* bucket, place again;
-    4. spill the residue (both candidate buckets full — rare below ~0.9
+    1. phase 1: place up to ``bucket_size`` keys per *primary* bucket —
+       each key takes the rank-th free slot of its bucket segment, read
+       from the unpacked rows of the buckets the batch touches and
+       committed as packed-word writes (the table is never unpacked
+       whole, so temporaries stay O(batch) at any table size);
+    2. phase 2: re-sort the overflow by *alternate* bucket, place again;
+    3. spill the residue (both candidate buckets full — rare below ~0.9
        load) into the general eviction round loop;
-    5. restore original batch order for ``ok``/stats outputs (the sorted
+    4. restore original batch order for ``ok``/stats outputs (the sorted
        view never escapes).
 
     ``stats.rounds`` counts the two bulk phases plus the residue loop's
@@ -1021,16 +1029,11 @@ def insert_bulk(
     tag1 = pol.place_tag(base_tag, jnp.zeros((n,), bool))
     tag2 = pol.place_tag(base_tag, jnp.ones((n,), bool))
 
-    tags_flat = L.unpack_words(state.table, lay.fp_bits)      # per-slot view
-
-    tags_flat, placed1 = _bulk_place_phase(
-        config, tags_flat, i1, tag1, pending)
+    table, placed1 = _bulk_place_phase(config, state.table, i1, tag1, pending)
     pending = pending & ~placed1
-    tags_flat, placed2 = _bulk_place_phase(
-        config, tags_flat, i2, tag2, pending)
+    table, placed2 = _bulk_place_phase(config, table, i2, tag2, pending)
     pending = pending & ~placed2
 
-    table = L.pack_tags(tags_flat, lay.fp_bits)
     placed = placed1 | placed2
     count = state.count + jnp.sum(placed, dtype=jnp.int32)
 
@@ -1100,7 +1103,7 @@ def delete(
         slot = jnp.where(f1, s1, s2)
         words = jnp.where(f1[:, None], words1, words2)
         widx, sw = L.slot_to_word(slot, lay)
-        word = jnp.take_along_axis(words, widx[:, None], axis=1)[:, 0]
+        word = L.pick(words, widx, 1)
         desired = L.replace_tag(word, sw, jnp.zeros((n,), jnp.uint32),
                                 lay.fp_bits)
         addr = L.word_addr(bucket, widx, lay)

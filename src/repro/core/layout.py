@@ -14,8 +14,10 @@ so one vector load covers a whole bucket (the TPU analogue of the paper's
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -156,11 +158,71 @@ def replace_tag(
 # Find start at a fingerprint-derived pseudo-random offset).
 # ---------------------------------------------------------------------------
 
+_ROW_WORDS = 128  # one TPU lane row of uint32 words (512 bytes)
+
+
 def gather_bucket_words(table: jnp.ndarray, bucket: jnp.ndarray, layout: BucketLayout) -> jnp.ndarray:
-    """Gather the packed words of each bucket: -> uint32[..., words_per_bucket]."""
-    base = bucket.astype(jnp.uint32) * _U32(layout.words_per_bucket)
-    offs = jnp.arange(layout.words_per_bucket, dtype=jnp.uint32)
-    return table[(base[..., None] + offs).astype(jnp.int32)]
+    """Gather the packed words of each bucket: -> uint32[..., words_per_bucket].
+
+    Lowered for a TPU, and where the table splits into whole 128-word rows,
+    each bucket is read as the one row holding it (:func:`gather_by_row`):
+    on a TPU v5e that is about 10x faster than gathering every word on its
+    own (13 ms against 123 ms for 2^20 buckets of a 2 GiB table). Elsewhere
+    each word is gathered (:func:`gather_by_word`), which moves 16x fewer
+    bytes. Both give the same words.
+    """
+    if _ROW_WORDS % layout.words_per_bucket or layout.num_words % _ROW_WORDS:
+        return gather_by_word(table, bucket, layout)
+    return jax.lax.platform_dependent(
+        table, bucket,
+        default=functools.partial(gather_by_word, layout=layout),
+        tpu=functools.partial(gather_by_row, layout=layout))
+
+
+def gather_by_word(table: jnp.ndarray, bucket: jnp.ndarray,
+                   layout: BucketLayout) -> jnp.ndarray:
+    """One scalar gather per packed word of each bucket."""
+    wpb = layout.words_per_bucket
+    offs = jnp.arange(wpb, dtype=jnp.int32)
+    return table[bucket.astype(jnp.int32)[..., None] * wpb + offs]
+
+
+def gather_by_row(table: jnp.ndarray, bucket: jnp.ndarray,
+                  layout: BucketLayout) -> jnp.ndarray:
+    """Gather the 128-word row holding each bucket, then select its words.
+
+    Needs ``num_words`` a multiple of 128 and ``words_per_bucket`` a divisor
+    of 128; viewing the flat table as ``[rows, 128]`` is then a bitcast.
+    """
+    wpb = layout.words_per_bucket
+    per_row = _ROW_WORDS // wpb
+    bucket = bucket.astype(jnp.int32)
+    rows = table.reshape(-1, _ROW_WORDS)[bucket // per_row]   # [..., 128]
+    first = ((bucket % per_row) * wpb)[..., None]
+    lane = jnp.arange(_ROW_WORDS, dtype=jnp.int32)
+    # One masked lane-reduction per word keeps every temporary 128 lanes
+    # wide (a [..., per_row, wpb] view would pad wpb up to 128 lanes).
+    return jnp.stack([jnp.sum(jnp.where(lane == first + j, rows, _U32(0)),
+                              axis=-1, dtype=jnp.uint32)
+                      for j in range(wpb)], axis=-1)
+
+
+def pick(values: jnp.ndarray, index: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """``values`` indexed by ``index`` along ``axis`` (axis removed).
+
+    ``index`` has the shape of ``values`` without ``axis`` (or broadcasts to
+    it) and lies in ``[0, values.shape[axis])``. Same result as
+    ``take_along_axis`` with the axis squeezed, computed as a one-hot select
+    and reduce: a gather along a short axis runs ~10x slower on a TPU than
+    reading the whole row.
+    """
+    axis = axis % values.ndim
+    lanes = jnp.arange(values.shape[axis], dtype=jnp.int32).reshape(
+        [-1 if d == axis else 1 for d in range(values.ndim)])
+    hit = lanes == jnp.expand_dims(index.astype(jnp.int32), axis)
+    if values.dtype == jnp.bool_:
+        return jnp.any(hit & values, axis=axis)
+    return jnp.sum(jnp.where(hit, values, 0), axis=axis, dtype=values.dtype)
 
 
 def bucket_tags(table: jnp.ndarray, bucket: jnp.ndarray, layout: BucketLayout) -> jnp.ndarray:
@@ -180,24 +242,25 @@ def first_true_circular(flags: jnp.ndarray, start: jnp.ndarray) -> Tuple[jnp.nda
     Returns (found: bool[...], slot: int32[...] absolute index).
     """
     b = flags.shape[-1]
-    idx = (start[..., None] + jnp.arange(b, dtype=jnp.int32)) % b
-    rot = jnp.take_along_axis(flags, idx, axis=-1)
-    found = jnp.any(rot, axis=-1)
-    first_rel = jnp.argmax(rot, axis=-1).astype(jnp.int32)
-    slot = (start + first_rel) % b
+    # Circular distance of every lane from ``start``; the first True lane is
+    # the one at the least distance. A min-reduction, not a rotating gather:
+    # the TPU compiler handles a gather along the minor axis slowly.
+    dist = (jnp.arange(b, dtype=jnp.int32) - start[..., None]) % b
+    first_rel = jnp.min(jnp.where(flags, dist, b), axis=-1)
+    found = first_rel < b
+    slot = (start + jnp.where(found, first_rel, 0)) % b
     return found, slot
 
 
 # ---------------------------------------------------------------------------
 # Segmented-scan helpers for the bulk-build insertion path (DESIGN.md §6).
 #
-# ``unpack_words`` applied to the *flat* table is already the per-slot view in
-# global slot order (slot s of bucket b lives at flat index b*bucket_size + s),
-# so a bulk placement round is: unpack table -> scatter one tag per free slot
-# -> pack. The helpers below compute, for a batch sorted by destination
-# bucket, each key's rank within its bucket segment and the bucket's rank-th
-# free slot — which together make whole-bucket commits conflict-free by
-# construction (every key owns a distinct slot).
+# A bulk placement round sorts the batch by destination bucket, unpacks only
+# the rows of the buckets it touches, and adds each placed tag into its
+# packed word. The helpers below compute each key's rank within its bucket
+# segment and the bucket's rank-th free slot — which together make
+# whole-bucket commits conflict-free by construction (every key owns a
+# distinct, empty lane).
 # ---------------------------------------------------------------------------
 
 def segment_ranks(sorted_ids: jnp.ndarray) -> jnp.ndarray:
@@ -207,8 +270,14 @@ def segment_ranks(sorted_ids: jnp.ndarray) -> jnp.ndarray:
     0, 1, 2, ... restarting at every segment boundary.
     """
     n = sorted_ids.shape[0]
-    first = jnp.searchsorted(sorted_ids, sorted_ids, side="left")
-    return jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
+    if n == 0:
+        return jnp.zeros((0,), jnp.int32)
+    idx = jnp.arange(n, dtype=jnp.int32)
+    starts = jnp.concatenate([jnp.ones((1,), bool),
+                              sorted_ids[1:] != sorted_ids[:-1]])
+    # Index of each run's first element, carried forward by a running max
+    # (a binary search per element costs 300x more on a TPU).
+    return idx - jax.lax.cummax(jnp.where(starts, idx, 0))
 
 
 def nth_free_slot(btags: jnp.ndarray, rank: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:

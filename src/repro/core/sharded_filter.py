@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat
 from .cuckoo_filter import CuckooConfig, CuckooState
 from .cuckoo_filter import apply_ops as _apply_ops
 from .cuckoo_filter import delete as _delete
@@ -58,6 +57,7 @@ from .cuckoo_filter import insert as _insert
 from .cuckoo_filter import insert_bulk as _insert_bulk
 from .cuckoo_filter import query as _query
 from .hashing import fmix32, normalize_keys
+from .layout import segment_ranks
 
 _U32 = np.uint32
 _SHARD_SALT = _U32(0x51ED270C)
@@ -242,15 +242,13 @@ def _route(config: ShardedCuckooConfig, keys: jnp.ndarray, cap: int,
     layout.
     """
     P = config.partitions
-    n = keys.shape[0]
     dest = partition_of(config, keys)
     if valid is not None:
         dest = jnp.where(valid.astype(bool), dest, P)
     order = jnp.argsort(dest, stable=True)
     dest_s = dest[order]
     keys_s = keys[order]
-    first_of_group = jnp.searchsorted(dest_s, dest_s, side="left")
-    idx_in_group = jnp.arange(n, dtype=jnp.int32) - first_of_group
+    idx_in_group = segment_ranks(dest_s)
     routed = (idx_in_group < cap) & (dest_s < P)
     slot = jnp.where(routed, dest_s * cap + idx_in_group, P * cap)
     bins = jnp.zeros((P * cap, 2), jnp.uint32).at[slot].set(keys_s, mode="drop")
@@ -392,10 +390,11 @@ class ShardedCuckooFilter:
             fn = _make_sharded_op(self.config, op, self.local_batch,
                                   dedup_within_batch=dedup)
             n_in = 5 if op == "apply_ops" else 4
-            mapped = compat.shard_map(
+            mapped = jax.shard_map(
                 fn, mesh=self.mesh,
                 in_specs=(P(ax),) * n_in,
                 out_specs=(P(ax), P(ax), P(ax), P(ax)),
+                check_vma=False,
             )
             self._ops[key] = jax.jit(mapped)
         return self._ops[key]

@@ -2,8 +2,10 @@
 
 Layout per kernel: ``<name>.py`` holds the ``pl.pallas_call`` + BlockSpec
 tiling, ``ops.py`` the jit'd public wrappers, ``ref.py`` the pure-jnp
-oracles. All kernels validate in interpret mode on CPU (this container) and
-target TPU VMEM-resident tables (the paper's L2-resident regime analogue).
+oracles. The kernels target VMEM-resident tables (the paper's L2-resident
+regime analogue). Interpret-mode tests on the CPU check their arithmetic
+only: the v5e compiler refuses every cuckoo, Bloom and k-mer kernel here and
+accepts ``hash64`` (tests/test_tpu_compile.py). The served path calls none.
 """
 
 from . import ops, ref  # noqa: F401

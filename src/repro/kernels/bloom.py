@@ -35,7 +35,7 @@ def _query_kernel(config: BloomConfig, table_ref, keys_lo_ref, keys_hi_ref,
 def bloom_query_pallas(config: BloomConfig, table: jnp.ndarray,
                        keys_lo: jnp.ndarray, keys_hi: jnp.ndarray,
                        *, block_keys: int = 1024,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     n = keys_lo.shape[0]
     assert n % block_keys == 0
     kernel = functools.partial(_query_kernel, config)
@@ -82,7 +82,7 @@ def bloom_insert_pallas(config: BloomConfig, table: jnp.ndarray,
                         keys_lo: jnp.ndarray, keys_hi: jnp.ndarray,
                         valid: jnp.ndarray | None = None,
                         *, block_keys: int = 256,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool) -> jnp.ndarray:
     n = keys_lo.shape[0]
     assert n % block_keys == 0
     if valid is None:

@@ -86,7 +86,7 @@ def cuckoo_insert_pallas(config: CuckooConfig, table: jnp.ndarray,
                          keys_lo: jnp.ndarray, keys_hi: jnp.ndarray,
                          valid: jnp.ndarray | None = None,
                          *, block_keys: int = 256,
-                         interpret: bool = True):
+                         interpret: bool):
     """Direct-insert a key stream; returns (table', ok uint32[n]).
 
     ok==0 keys need the eviction path (core.cuckoo_filter.insert).
@@ -188,7 +188,7 @@ def cuckoo_insert_fused_pallas(config: CuckooConfig, table: jnp.ndarray,
                                keys_lo: jnp.ndarray, keys_hi: jnp.ndarray,
                                valid: jnp.ndarray | None = None,
                                *, block_keys: int = 256,
-                               interpret: bool = True):
+                               interpret: bool):
     """Fused-SWAR variant of :func:`cuckoo_insert_pallas` — same contract,
     bit-identical results (the roofline suite measures both)."""
     n = keys_lo.shape[0]
@@ -309,7 +309,7 @@ def cuckoo_insert_bulk_pallas(config: CuckooConfig, table: jnp.ndarray,
                               keys_lo: jnp.ndarray, keys_hi: jnp.ndarray,
                               valid: jnp.ndarray | None = None,
                               *, block_keys: int = 256,
-                              interpret: bool = True):
+                              interpret: bool):
     """Bucket-major direct insert; callers must pass keys sorted by primary
     bucket (``prepare_keys``'s ``i1``). Returns (table', ok uint32[n])."""
     n = keys_lo.shape[0]

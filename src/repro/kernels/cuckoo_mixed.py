@@ -131,7 +131,7 @@ def cuckoo_mixed_pallas(config: CuckooConfig, table: jnp.ndarray,
                         ops: jnp.ndarray,
                         valid: jnp.ndarray | None = None,
                         *, block_keys: int = 256,
-                        interpret: bool = True):
+                        interpret: bool):
     """Fused mixed-op pass; returns (table', ok uint32[n]).
 
     ``ops`` is int32[n] op codes (0 query / 1 insert / 2 delete); ``ok``

@@ -118,7 +118,7 @@ def _query_call(kernel_body, config: CuckooConfig, table: jnp.ndarray,
 def cuckoo_query_pallas(config: CuckooConfig, table: jnp.ndarray,
                         keys_lo: jnp.ndarray, keys_hi: jnp.ndarray,
                         *, block_keys: int = 1024,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool) -> jnp.ndarray:
     """Query ``n`` keys against a VMEM-resident filter table.
 
     n must be a multiple of ``block_keys`` (callers pad; see ops.py).
@@ -131,7 +131,7 @@ def cuckoo_query_pallas(config: CuckooConfig, table: jnp.ndarray,
 def cuckoo_query_fused_pallas(config: CuckooConfig, table: jnp.ndarray,
                               keys_lo: jnp.ndarray, keys_hi: jnp.ndarray,
                               *, block_keys: int = 1024,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: bool) -> jnp.ndarray:
     """Fused-SWAR variant of :func:`cuckoo_query_pallas` — same contract.
 
     Kept alongside the unpack-based kernel so the roofline suite can

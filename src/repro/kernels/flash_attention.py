@@ -90,7 +90,7 @@ def _flash_kernel(causal: bool, window, scale: float, blk_q: int, blk_k: int,
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
                            blk_q: int = 512, blk_k: int = 512,
-                           interpret: bool = True):
+                           interpret: bool):
     """Fused attention forward.
 
     q: [BK, g, Sq, D]; k: [BK, Sk, D]; v: [BK, Sk, Dv] where BK = B * KVH

@@ -24,7 +24,7 @@ def _hash_kernel(seed: int, keys_lo_ref, keys_hi_ref, out_hi_ref, out_lo_ref):
 
 def hash64_pallas(keys_lo: jnp.ndarray, keys_hi: jnp.ndarray, *,
                   seed: int = 0, block_keys: int = 2048,
-                  interpret: bool = True):
+                  interpret: bool):
     """xxHash64 of n packed keys -> (hi, lo) uint32[n]."""
     n = keys_lo.shape[0]
     assert n % block_keys == 0

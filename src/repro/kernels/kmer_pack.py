@@ -37,7 +37,7 @@ def _kmer_kernel(k: int, block: int, bases_ref, out_hi_ref, out_lo_ref):
 
 
 def kmer_pack_pallas(bases: jnp.ndarray, k: int = 31, *,
-                     block: int = 1024, interpret: bool = True):
+                     block: int = 1024, interpret: bool):
     """bases: uint32[n] 2-bit codes, n a multiple of ``block``.
 
     Returns (hi, lo) uint32[n]; positions > n-k are computed from zero

@@ -45,6 +45,10 @@ from .shardings import (  # noqa: E402
     make_param_shardings,
 )
 
+# The chip these projections are for: the CPU-compiled HLO is costed
+# against this device's published peaks.
+TARGET_KIND = "TPU v5 lite"
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
 
@@ -147,16 +151,7 @@ def analyse(compiled, meta, *, keep_hlo: bool = False):
     # 61-layer scanned stack ~61x. Validated against known matmuls.
     tc = HC.analyse_text(txt, n_dev)
     colls = tc["collectives"]
-    terms = {
-        "compute_s": tc["flops"] / H.PEAK_FLOPS,
-        "memory_s": tc["bytes"] / H.HBM_BW,
-        "collective_s": (sum(s["wire_bytes"] for s in colls.values())
-                         / (H.ICI_LINKS * H.ICI_BW)),
-        "hlo_flops": tc["flops"],
-        "hlo_bytes": tc["bytes"],
-        "collective_wire_bytes": sum(s["wire_bytes"]
-                                     for s in colls.values()),
-    }
+    terms = H.roofline_terms(tc["flops"], tc["bytes"], colls, TARGET_KIND)
 
     # MODEL_FLOPS: 6/2 N D (active params for MoE) + analytic attention/SSM
     # terms (hlo_analysis.analytic_model_flops)
@@ -173,7 +168,7 @@ def analyse(compiled, meta, *, keep_hlo: bool = False):
             "alias_bytes": mem.alias_size_in_bytes,
             "fits_16gb": (mem.argument_size_in_bytes - mem.alias_size_in_bytes
                           + mem.output_size_in_bytes + mem.temp_size_in_bytes)
-            < H.HBM_PER_CHIP,
+            < H.peaks(TARGET_KIND)["hbm_bytes"],
         },
         "cost_xla_unscaled": {k: float(v) for k, v in cost.items()
                               if "flops" in k or k == "bytes accessed"},

@@ -6,7 +6,8 @@ all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 contributes its result-shape bytes, scaled to *wire bytes per device* with
 the standard ring-algorithm factors and the parsed replica-group size.
 
-Hardware constants are TPU v5e-class (DESIGN.md §6).
+Hardware peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``;
+a device that is not in the table raises instead of borrowing another's.
 """
 
 from __future__ import annotations
@@ -15,12 +16,29 @@ import re
 from collections import defaultdict
 from typing import Dict, List
 
-# v5e-class constants
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (~3 usable links/chip on a torus)
-ICI_LINKS = 3
-HBM_PER_CHIP = 16 * 2**30
+# Published per-chip peaks. Source: Google Cloud documentation, "TPU v5e"
+# (system architecture): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of inter-chip interconnect. ``hbm_bytes`` is the
+# capacity the v5e compiler itself enforces (17179869184 bytes).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes": 16 * 2**30,
+        "hbm_bytes_per_s": 819e9,
+        "ici_bytes_per_s": 1600e9 / 8,
+    },
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip of ``device_kind`` — never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add them "
+            f"to PEAKS with their source (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -94,16 +112,15 @@ def collective_stats(hlo_text: str, n_devices: int) -> Dict[str, Dict]:
     return dict(stats)
 
 
-def roofline_terms(cost: Dict, colls: Dict[str, Dict],
-                   n_devices: int) -> Dict[str, float]:
+def roofline_terms(flops: float, bytes_hbm: float, colls: Dict[str, Dict],
+                   device_kind: str) -> Dict[str, float]:
     """Three roofline terms in seconds (per device, per step)."""
-    flops = float(cost.get("flops", 0.0))
-    bytes_hbm = float(cost.get("bytes accessed", 0.0))
+    pk = peaks(device_kind)
     wire = float(sum(s["wire_bytes"] for s in colls.values()))
     return {
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": bytes_hbm / HBM_BW,
-        "collective_s": wire / (ICI_LINKS * ICI_BW),
+        "compute_s": flops / pk["bf16_flops_per_s"],
+        "memory_s": bytes_hbm / pk["hbm_bytes_per_s"],
+        "collective_s": wire / pk["ici_bytes_per_s"],
         "hlo_flops": flops,
         "hlo_bytes": bytes_hbm,
         "collective_wire_bytes": wire,

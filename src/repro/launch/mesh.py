@@ -8,22 +8,21 @@ count via XLA_FLAGS before any jax initialisation.
 from __future__ import annotations
 
 import jax
-
-from repro.core import compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **compat.auto_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(devices: int = 8):
     """Small CPU mesh for integration tests (data x model = devices)."""
     model = 2 if devices % 2 == 0 else 1
     return jax.make_mesh((devices // model, model), ("data", "model"),
-                         **compat.auto_axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple:

@@ -47,7 +47,7 @@ def test_flash_kernel_matches_oracle(B, KVH, g, Sq, Sk, D, Dv, causal,
                            chunk_q=64, chunk_k=64)
     qk, kk, vk = _to_kernel_layout(q, k, v)
     got = flash_attention_pallas(qk, kk, vk, causal=causal, window=window,
-                                 blk_q=bq, blk_k=bk)
+                                 blk_q=bq, blk_k=bk, interpret=True)
     got = _from_kernel_layout(got, B, KVH, g, Sq, Dv)
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),

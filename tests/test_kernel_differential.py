@@ -81,7 +81,8 @@ def _jit(fn, cfg):
 
 @functools.lru_cache(maxsize=None)
 def _jit_blk(fn, cfg):
-    return jax.jit(functools.partial(fn, cfg, block_keys=BLOCK))
+    return jax.jit(functools.partial(fn, cfg, block_keys=BLOCK,
+                                     interpret=True))
 
 
 def _filled(cfg: CuckooConfig, rng, occupancy: float):

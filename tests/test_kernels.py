@@ -47,7 +47,7 @@ def test_cuckoo_query_kernel_sweep(nb, f, b, pol, hk, n, blk):
     keys = rand_keys(rng, n)
     ok, _ = filt.insert(keys[: n // 2])
     got = cuckoo_query_pallas(cfg, filt.state.table, keys[:, 0], keys[:, 1],
-                              block_keys=blk)
+                              block_keys=blk, interpret=True)
     want = R.cuckoo_query_ref(cfg, filt.state.table, keys[:, 0], keys[:, 1])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0)
     # inserted keys must be hits — guaranteed only for failure-free batches
@@ -64,7 +64,7 @@ def test_cuckoo_insert_kernel_sweep(nb, f, b, pol, hk, n, blk):
     table = cfg.layout.empty_table()
     keys = rand_keys(rng, n)
     t_got, ok_got = cuckoo_insert_pallas(cfg, table, keys[:, 0], keys[:, 1],
-                                         block_keys=blk)
+                                         block_keys=blk, interpret=True)
     t_want, ok_want = R.cuckoo_insert_ref(cfg, table, keys[:, 0], keys[:, 1])
     np.testing.assert_allclose(np.asarray(t_got), np.asarray(t_want), rtol=0)
     np.testing.assert_allclose(np.asarray(ok_got), np.asarray(ok_want), rtol=0)
@@ -83,7 +83,7 @@ def test_cuckoo_insert_bulk_kernel_sweep(nb, f, b, pol, hk, n, blk):
     _, i1, _ = prepare_keys(cfg, keys)
     ks = keys[jnp.argsort(i1.astype(jnp.int32), stable=True)]
     t_got, ok_got = cuckoo_insert_bulk_pallas(cfg, table, ks[:, 0], ks[:, 1],
-                                              block_keys=blk)
+                                              block_keys=blk, interpret=True)
     t_want, ok_want = R.cuckoo_insert_ref(cfg, table, ks[:, 0], ks[:, 1])
     np.testing.assert_array_equal(np.asarray(t_got), np.asarray(t_want))
     np.testing.assert_array_equal(np.asarray(ok_got), np.asarray(ok_want))
@@ -110,7 +110,7 @@ def test_cuckoo_insert_kernel_respects_valid_mask():
     keys = rand_keys(rng, 128)
     valid = jnp.asarray(([1] * 64) + ([0] * 64), jnp.uint32)
     t, ok = cuckoo_insert_pallas(cfg, table, keys[:, 0], keys[:, 1], valid,
-                                 block_keys=64)
+                                 block_keys=64, interpret=True)
     assert np.asarray(ok)[:64].all() and not np.asarray(ok)[64:].any()
     # table must contain exactly the 64 valid keys' fingerprints
     t2, _ = R.cuckoo_insert_ref(cfg, table, keys[:64, 0], keys[:64, 1])
@@ -152,11 +152,11 @@ def test_bloom_kernels_sweep(blocks, wpb, k, n, blk):
     table = cfg.init().table
     keys = rand_keys(rng, n)
     t_got = bloom_insert_pallas(cfg, table, keys[:, 0], keys[:, 1],
-                                block_keys=blk)
+                                block_keys=blk, interpret=True)
     t_want = R.bloom_insert_ref(cfg, table, keys[:, 0], keys[:, 1])
     np.testing.assert_array_equal(np.asarray(t_got), np.asarray(t_want))
     q_got = bloom_query_pallas(cfg, t_got, keys[:, 0], keys[:, 1],
-                               block_keys=blk)
+                               block_keys=blk, interpret=True)
     q_want = R.bloom_query_ref(cfg, t_want, keys[:, 0], keys[:, 1])
     np.testing.assert_array_equal(np.asarray(q_got), np.asarray(q_want))
     assert np.asarray(q_got).all()  # no false negatives
@@ -167,7 +167,7 @@ def test_hash64_kernel(n, blk, seed):
     rng = np.random.default_rng(n)
     keys = rand_keys(rng, n)
     hi_g, lo_g = hash64_pallas(keys[:, 0], keys[:, 1], seed=seed,
-                               block_keys=blk)
+                               block_keys=blk, interpret=True)
     hi_w, lo_w = R.hash64_ref(keys[:, 0], keys[:, 1], seed=seed)
     np.testing.assert_array_equal(np.asarray(hi_g), np.asarray(hi_w))
     np.testing.assert_array_equal(np.asarray(lo_g), np.asarray(lo_w))
@@ -178,7 +178,8 @@ def test_hash64_kernel(n, blk, seed):
 def test_kmer_pack_kernel(n, k, blk):
     rng = np.random.default_rng(k)
     bases = jnp.asarray(rng.integers(0, 4, size=n), jnp.uint32)
-    hi_g, lo_g = kmer_pack_pallas(bases, k=k, block=blk)
+    hi_g, lo_g = kmer_pack_pallas(bases, k=k, block=blk,
+                                  interpret=True)
     hi_w, lo_w = R.kmer_pack_ref(bases, k=k)
     m = n - k + 1
     np.testing.assert_array_equal(np.asarray(hi_g)[:m], np.asarray(hi_w)[:m])

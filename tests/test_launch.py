@@ -11,7 +11,7 @@ import pytest
 
 from repro.configs import ARCHS, get_config
 from repro.launch import hlo_cost as HC
-from repro.launch.hlo_analysis import analytic_model_flops
+from repro.launch.hlo_analysis import analytic_model_flops, peaks
 from repro.launch.input_specs import SHAPES, SKIPS, input_specs, live_cells
 
 
@@ -85,10 +85,10 @@ def test_param_shardings_divisibility_guards():
     from repro.launch.shardings import make_param_shardings
     from repro.models import build_model
 
-    from repro.core import compat
+    from jax.sharding import AxisType
 
     mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         **compat.auto_axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)
     for arch in ("hubert_xlarge", "mamba2_130m", "mixtral_8x22b"):
         cfg = get_config(arch)
         model = build_model(cfg)
@@ -113,3 +113,11 @@ def test_dryrun_one_cell_subprocess():
         cwd=os.path.join(os.path.dirname(__file__), ".."))
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "[ok]" in proc.stdout
+
+
+def test_peaks_are_keyed_by_device_kind():
+    v5e = peaks("TPU v5 lite")
+    assert v5e["hbm_bytes_per_s"] == 819e9
+    assert v5e["bf16_flops_per_s"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")

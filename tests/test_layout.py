@@ -1,5 +1,6 @@
 """SWAR primitives and packed layout vs naive unpack oracles."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -89,3 +90,16 @@ def test_gather_bucket_words():
     words = L.gather_bucket_words(table, jnp.asarray([2, 0], jnp.uint32), lay)
     np.testing.assert_array_equal(np.asarray(words),
                                   [[4, 5], [0, 1]])
+
+
+@pytest.mark.parametrize("nb,b,f", [(1 << 10, 16, 16), (1 << 10, 8, 8),
+                                    (64, 4, 32), (256, 32, 16)])
+def test_row_gather_equals_word_gather(nb, b, f):
+    """The TPU lowering's row gather reads exactly the words of each bucket."""
+    lay = L.BucketLayout(nb, b, f)
+    table = jax.random.bits(jax.random.key(nb + b), (lay.num_words,),
+                            jnp.uint32)
+    buckets = jax.random.randint(jax.random.key(f), (37, 5), 0, nb)
+    np.testing.assert_array_equal(
+        np.asarray(L.gather_by_row(table, buckets, lay)),
+        np.asarray(L.gather_by_word(table, buckets, lay)))
