@@ -37,7 +37,7 @@ from . import (
     throughput,
     tiering,
 )
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import disable_tpu_logs, enable_compile_cache
 
 from .common import JSON_RECORDS, ROWS
 
@@ -100,6 +100,7 @@ def main(argv=None) -> int:
     if args.json_dir is not None:
         json_dir = pathlib.Path(args.json_dir)
         json_dir.mkdir(parents=True, exist_ok=True)
+    disable_tpu_logs()
     enable_compile_cache()
     print("name,us_per_call,derived")
     errored = []

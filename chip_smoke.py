@@ -19,8 +19,8 @@ One-chip phases, one line each (wall time, table bytes, load, peak bytes):
 1. ``fill``    bulk-insert to load 0.95 with ``handle.insert(bulk=True)``;
 2. ``query``   query every inserted key;
 3. ``absent``  query as many never-inserted keys;
-4. ``delete``  delete the first ``--delete-fraction`` of the inserted keys,
-   then re-query the kept and the deleted keys;
+4. ``delete``  delete the first ``DELETE_FRACTION`` (1/8) of the inserted
+   keys, then re-query the kept and the deleted keys;
 5. ``service`` ``--dispatches`` full micro-batches of a mixed
    query/insert/delete stream through ``FilterService`` (``max_in_flight=2``,
    so donation and the in-flight window run), and a replay of every op on a
@@ -40,9 +40,13 @@ Checks (any failure exits 1 and prints no result line):
   inserts, and deletes of present keys (all must be true).
 
 ``--chips 4`` runs only the sharded phase: ``sharded-cuckoo`` over a 4-device
-mesh built from ``jax.devices()``, about 1 GiB of table per chip, bulk-filled
-to 0.95; its answers on a shared sample are checked against a one-chip
+mesh built from ``jax.devices()``, 1 GiB of table per chip, bulk-filled to
+``LOAD_4`` (0.25: the one-chip phase covers the fill to 0.95, and this phase
+checks what exists only across chips at a quarter of that phase's fill
+time); its answers on a shared sample are checked against a one-chip
 ``cuckoo`` holding the same sample, and every shard's count must be non-zero.
+
+The fill prints a progress line (``# fill: ...``) every 128 batches.
 
 The last line of standard output is ``{"ok": true, "device": {...}}``, and it
 is printed only on a TPU. Without a TPU the script fails, unless
@@ -69,13 +73,15 @@ import numpy as np  # noqa: E402
 
 from repro import amq  # noqa: E402
 from repro.amq.protocol import OP_DELETE, OP_INSERT, OP_QUERY, OpBatch  # noqa: E402
-from repro.compile_cache import enable_compile_cache  # noqa: E402
+from repro.compile_cache import disable_tpu_logs, enable_compile_cache  # noqa: E402
 from repro.core import bits64 as b64  # noqa: E402
 from repro.core import keys_to_numpy  # noqa: E402
 from repro.core.cuckoo_filter import CuckooConfig  # noqa: E402
 
 PRESENT, ABSENT, NEW = 0, 1, 2
 LOAD = 0.95
+LOAD_4 = 0.25          # the --chips 4 fill, per chip
+DELETE_FRACTION = 0.125
 FPR_BAND = (0.5, 1.5)  # absent-key hit rate / handle.expected_fpr()
 FPR_SIGMAS = 6.0
 _FMIX1, _FMIX2 = 0xFF51AFD7ED558CCD, 0xC4CEB9FE1A85EC53
@@ -185,10 +191,9 @@ def fill(handle, seed, stream, n, width, progress_every=128):
         rep = handle.insert(keys, bulk=True, valid=valid)
         failed += jnp.sum(valid & ~(rep.ok & rep.routed), dtype=jnp.int32)
         rounds = jnp.maximum(rounds, rep.rounds)
-        if (i + 1) % progress_every == 0:  # syncs: a progress mark on stderr
+        if (i + 1) % progress_every == 0:  # syncs: a progress mark
             print(f"# fill: {(i + 1) * width} keys, {int(failed)} failed, "
-                  f"{time.perf_counter() - t0:.1f}s", file=sys.stderr,
-                  flush=True)
+                  f"{time.perf_counter() - t0:.1f}s", flush=True)
     return int(failed), int(rounds)
 
 
@@ -254,7 +259,7 @@ def one_chip(args, devices) -> None:
         "absent", absent, n_fill, handle.expected_fpr()))
 
     # 4. delete a fraction, re-query kept and deleted keys.
-    n_del = int(n_fill * args.delete_fraction)
+    n_del = int(n_fill * DELETE_FRACTION)
     t0 = time.perf_counter()
     removed = delete_range(handle, seed, PRESENT, 0, n_del, wq)
     check(removed == n_del, f"delete: {n_del - removed} of {n_del} failed")
@@ -384,10 +389,11 @@ def four_chips(args, devices) -> None:
     capacity = n_dev * int(LOAD * per_chip_slots)
     handle = amq.make("sharded-cuckoo", capacity=capacity, num_shards=n_dev,
                       fp_bits=16, bucket_size=16, policy="xor",
-                      hash_kind="xxhash64")
+                      hash_kind="xxhash64",
+                      capacity_factor=args.shard_bin_factor)
     check(handle.config.inner.shard.num_buckets == 1 << args.log2_buckets_4,
           "sharded: per-chip table is not the requested size")
-    n_fill = math.ceil(LOAD * handle.config.num_slots)
+    n_fill = math.ceil(LOAD_4 * handle.config.num_slots)
 
     t0 = time.perf_counter()
     failed, _ = fill(handle, seed, PRESENT, n_fill, args.fill_batch_4)
@@ -407,8 +413,9 @@ def four_chips(args, devices) -> None:
         num_buckets=1 << args.log2_buckets_sample, fp_bits=16,
         bucket_size=16, policy="xor", hash_kind="xxhash64"))
     n_s = math.ceil(LOAD * one.config.num_slots)
+    check(n_s <= n_fill, f"sample of {n_s} keys > {n_fill} inserted")
     t0 = time.perf_counter()
-    failed1, _ = fill(one, seed, PRESENT, n_s, args.fill_batch_4)
+    failed1, _ = fill(one, seed, PRESENT, n_s, args.fill_batch)
     check(failed1 == 0, f"one-chip sample fill: {failed1} failed")
     lines = {}
     for name, h in (("sharded", handle), ("one_chip", one)):
@@ -429,24 +436,26 @@ def parse_args(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="run on the CPU at a tiny size; prints no result")
-    ap.add_argument("--delete-fraction", type=float, default=0.125)
     args = ap.parse_args(argv)
     if args.cpu_rehearsal:
         sizes = dict(log2_buckets=12, log2_buckets_4=10,
-                     log2_buckets_sample=10, fill_batch=1 << 10,
+                     log2_buckets_sample=9, fill_batch=1 << 10,
                      fill_batch_4=1 << 10, query_batch=1 << 10,
                      service_batch=1 << 10, dispatches=32, sample_bits=0,
-                     min_sample=1 << 15)
+                     min_sample=1 << 15, shard_bin_factor=2.0)
     else:
         # 2^25 buckets x 16 slots x 16 bits = 1 GiB, on one chip and on
         # each of four: the size whose fill keeps the whole run well inside
-        # its 20-minute limit; 256 dispatches of 2^16 ops, 1/128 of the keys
-        # replayed on the reference.
+        # its 20-minute limit; 2^19 keys per chip per sharded fill batch,
+        # routed into bins 1.25x the mean a partition receives (over 70
+        # binomial sigmas at these widths; the tiny rehearsal needs 2x);
+        # 256 dispatches of 2^16 ops, 1/128 of the keys replayed on the
+        # reference.
         sizes = dict(log2_buckets=25, log2_buckets_4=25,
                      log2_buckets_sample=20, fill_batch=1 << 19,
-                     fill_batch_4=1 << 20, query_batch=1 << 20,
+                     fill_batch_4=1 << 21, query_batch=1 << 20,
                      service_batch=1 << 16, dispatches=256, sample_bits=7,
-                     min_sample=1 << 16)
+                     min_sample=1 << 16, shard_bin_factor=1.25)
     for k, v in sizes.items():
         setattr(args, k, v)
     return args
@@ -454,6 +463,7 @@ def parse_args(argv=None):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    disable_tpu_logs()
     enable_compile_cache()
     devices = jax.devices()
     platform, kind = devices[0].platform, devices[0].device_kind

@@ -9,10 +9,11 @@ cuckoo filter membership, with deletion demonstrating contamination removal
 
 import numpy as np
 
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import disable_tpu_logs, enable_compile_cache
 from repro.core import CuckooConfig, CuckooFilter
 from repro.data.kmer import kmer_keys, synthetic_genome
 
+disable_tpu_logs()
 enable_compile_cache()
 
 K = 31

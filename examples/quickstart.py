@@ -7,9 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import amq
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import disable_tpu_logs, enable_compile_cache
 from repro.core import CuckooConfig, keys_from_numpy
 
+disable_tpu_logs()
 enable_compile_cache()
 
 # 1. One registry, every filter family. Pick a backend by name and size it

@@ -12,11 +12,12 @@ import time
 import jax
 import numpy as np
 
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import disable_tpu_logs, enable_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serve import ServeEngine
 
+disable_tpu_logs()
 enable_compile_cache()
 
 cfg = get_config("gemma2_2b").reduced()

@@ -24,7 +24,7 @@ import tempfile
 import jax
 import numpy as np
 
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import disable_tpu_logs, enable_compile_cache
 from repro.configs import get_config
 from repro.core import CuckooConfig
 from repro.data import DataConfig, DedupConfig, dedup_batch, make_batch
@@ -44,6 +44,7 @@ ap.add_argument("--device-budget-bytes", type=int, default=None,
                 help="cap the dedup filter's device footprint; older "
                      "levels tier out to host RAM (DESIGN.md §12)")
 args = ap.parse_args()
+disable_tpu_logs()
 enable_compile_cache()
 
 cfg = get_config("mamba2_130m")

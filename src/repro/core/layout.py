@@ -127,17 +127,6 @@ def unpack_words(words: jnp.ndarray, fp_bits: int) -> jnp.ndarray:
     return tags.reshape(*words.shape[:-1], words.shape[-1] * tpw)
 
 
-def pack_tags(tags: jnp.ndarray, fp_bits: int) -> jnp.ndarray:
-    """Inverse of unpack_words."""
-    tpw = 32 // fp_bits
-    t = tags.reshape(*tags.shape[:-1], tags.shape[-1] // tpw, tpw)
-    shifts = jnp.arange(tpw, dtype=jnp.uint32) * _U32(fp_bits)
-    return jnp.sum(
-        (t & _U32((1 << fp_bits) - 1)).astype(jnp.uint32) << shifts, axis=-1,
-        dtype=jnp.uint32,
-    )
-
-
 def extract_tag(word: jnp.ndarray, slot_in_word: jnp.ndarray, fp_bits: int) -> jnp.ndarray:
     """ExtractTag (paper Alg. 1 line 17)."""
     shift = (slot_in_word.astype(jnp.uint32) * _U32(fp_bits))
@@ -166,10 +155,11 @@ def gather_bucket_words(table: jnp.ndarray, bucket: jnp.ndarray, layout: BucketL
 
     Lowered for a TPU, and where the table splits into whole 128-word rows,
     each bucket is read as the one row holding it (:func:`gather_by_row`):
-    on a TPU v5e that is about 10x faster than gathering every word on its
-    own (13 ms against 123 ms for 2^20 buckets of a 2 GiB table). Elsewhere
-    each word is gathered (:func:`gather_by_word`), which moves 16x fewer
-    bytes. Both give the same words.
+    the v5e runs a gather of 8 scalar words per bucket far slower than one
+    of a whole row. Elsewhere each word is gathered (:func:`gather_by_word`):
+    on XLA-CPU the row gather slows the CI-gated benchmark suites 4x to 50x
+    (PERF.md, Findings). Both give the same words; the tests check that the
+    core ops agree under either.
     """
     if _ROW_WORDS % layout.words_per_bucket or layout.num_words % _ROW_WORDS:
         return gather_by_word(table, bucket, layout)
@@ -213,8 +203,8 @@ def pick(values: jnp.ndarray, index: jnp.ndarray, axis: int) -> jnp.ndarray:
     ``index`` has the shape of ``values`` without ``axis`` (or broadcasts to
     it) and lies in ``[0, values.shape[axis])``. Same result as
     ``take_along_axis`` with the axis squeezed, computed as a one-hot select
-    and reduce: a gather along a short axis runs ~10x slower on a TPU than
-    reading the whole row.
+    and reduce: a TPU runs a gather along a short axis much slower than a
+    pass over the whole row.
     """
     axis = axis % values.ndim
     lanes = jnp.arange(values.shape[axis], dtype=jnp.int32).reshape(
@@ -276,7 +266,7 @@ def segment_ranks(sorted_ids: jnp.ndarray) -> jnp.ndarray:
     starts = jnp.concatenate([jnp.ones((1,), bool),
                               sorted_ids[1:] != sorted_ids[:-1]])
     # Index of each run's first element, carried forward by a running max
-    # (a binary search per element costs 300x more on a TPU).
+    # (a binary search per element is a loop of gathers, slow on a TPU).
     return idx - jax.lax.cummax(jnp.where(starts, idx, 0))
 
 

@@ -39,7 +39,7 @@ import numpy as np  # noqa: E402
 
 from repro import amq  # noqa: E402
 from repro.amq.protocol import load_snapshot, save_snapshot  # noqa: E402
-from repro.compile_cache import enable_compile_cache  # noqa: E402
+from repro.compile_cache import disable_tpu_logs, enable_compile_cache  # noqa: E402
 
 
 def _parse_kw(pairs) -> dict:
@@ -270,6 +270,7 @@ def main(argv=None) -> int:
     p.set_defaults(fn=cmd_stats)
 
     args = ap.parse_args(argv)
+    disable_tpu_logs()
     enable_compile_cache()
     return args.fn(args)
 
