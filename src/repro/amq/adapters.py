@@ -122,6 +122,24 @@ class AMQAdapter:
     host_delete: Optional[Callable[..., Any]] = None
 
 
+def program_name(backend: str, op: str) -> str:
+    """The name a backend's op compiles under, less JAX's ``jit_`` prefix.
+
+    ``<backend>_<op>`` with ``-`` mapped to ``_``: ``cuckoo_query``,
+    ``sharded_cuckoo_apply_ops``. Trace readers find the programs by it.
+    """
+    return f"{backend}_{op}".replace("-", "_")
+
+
+def named(fn, name: str):
+    """``fn`` with ``__name__`` and ``__qualname__`` set to ``name``, which
+    ``jax.jit`` names its program after. Not ``functools.wraps``: that also
+    sets ``__wrapped__``, which ``jax.jit`` follows to read the signature it
+    donates arguments by."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _zero_stats(n):
     return jnp.zeros((n,), jnp.int32), jnp.zeros((), jnp.int32)
 
@@ -679,7 +697,7 @@ def _sharded_fn(config: ShardedAMQConfig, op: str, local_batch: int,
                            in_specs=(P(ax),) * n_in,
                            out_specs=(P(ax), P(ax), P(ax), P(ax)),
                            check_vma=False)
-    return jax.jit(mapped)
+    return jax.jit(named(mapped, program_name("sharded-cuckoo", op)))
 
 
 def _sharded_run(config, state, keys, op, valid, dedup=False, ops=None):

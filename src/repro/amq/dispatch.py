@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from .protocol import MixedReport
+from .spans import span
 
 
 class QueueFullError(RuntimeError):
@@ -107,17 +108,22 @@ def rung_for(m: int, ladder: Tuple[int, ...]) -> int:
 # Latency accounting: fixed log-spaced histograms (no per-op retention).
 # ---------------------------------------------------------------------------
 
-# Bucket upper bounds in seconds: 1us .. ~68s doubling, +inf overflow.
-_BUCKET_BOUNDS = tuple(1e-6 * 2.0 ** i for i in range(27)) + (float("inf"),)
+# Bucket upper bounds in seconds: 1us .. ~68s, four buckets per doubling
+# (each 2^(1/4) = 1.19x the last), +inf overflow.
+_BUCKET_STEPS_PER_DOUBLING = 4
+_BUCKET_BOUNDS = tuple(
+    1e-6 * 2.0 ** (i / _BUCKET_STEPS_PER_DOUBLING)
+    for i in range(26 * _BUCKET_STEPS_PER_DOUBLING + 1)) + (float("inf"),)
 
 
 class LatencyHistogram:
-    """Log2-bucketed latency histogram with percentile readout.
+    """Log-bucketed latency histogram with percentile readout.
 
-    Observations land in doubling buckets from 1us to ~68s (overflow bucket
-    above); percentiles report the bucket upper bound — a <=2x-granular,
-    O(1)-memory estimate, which is the right fidelity for SLO dashboards
-    (the alternative, retaining every sample, scales with traffic).
+    Observations land in buckets spaced by 2^(1/4) from 1us to ~68s
+    (overflow bucket above); percentiles report the bucket upper bound — at
+    most 19% above the true quantile, in O(1) memory, which is the right
+    fidelity for SLO dashboards (the alternative, retaining every sample,
+    scales with traffic).
     """
 
     __slots__ = ("counts", "total")
@@ -259,15 +265,19 @@ class Dispatch:
     The report's arrays stay un-concretised device values until first
     touch (double buffering — the host keeps packing while the device
     churns); the first touch blocks, caches the host arrays, and stamps
-    this batch's enqueue→ready latencies into the metrics.
+    this batch's enqueue→ready latencies into the metrics. ``seq`` numbers
+    the service's dispatches from 0 (the ``dispatch`` argument of the
+    ``amq.service.*`` spans).
     """
 
-    __slots__ = ("report", "_ok", "_routed", "_metrics", "_clock",
+    __slots__ = ("report", "seq", "_ok", "_routed", "_metrics", "_clock",
                  "_enqueued_at", "done")
 
     def __init__(self, report: MixedReport, metrics: ServiceMetrics,
-                 clock: Callable[[], float], enqueued_at: np.ndarray):
+                 clock: Callable[[], float], enqueued_at: np.ndarray,
+                 seq: int):
         self.report = report
+        self.seq = seq
         self._ok: Optional[np.ndarray] = None
         self._routed: Optional[np.ndarray] = None
         self._metrics = metrics
@@ -275,22 +285,27 @@ class Dispatch:
         self._enqueued_at = enqueued_at
         self.done = False
 
-    def _observe_ready(self) -> None:
-        if not self.done:
-            self.done = True
-            self._metrics.observe_ready(self._clock() - self._enqueued_at)
-            self._enqueued_at = None  # release; latencies are binned now
+    def _pull(self, array) -> np.ndarray:
+        """Host copy of one report array. The first pull blocks on the
+        device result (the ``amq.service.ready`` span) and stamps this
+        batch's enqueue→ready latencies."""
+        if self.done:
+            return np.asarray(array, bool)
+        with span("service.ready", dispatch=self.seq):
+            out = np.asarray(array, bool)
+        self.done = True
+        self._metrics.observe_ready(self._clock() - self._enqueued_at)
+        self._enqueued_at = None  # release; latencies are binned now
+        return out
 
     def ok(self) -> np.ndarray:
-        if self._ok is None:  # first touch blocks on the device result
-            self._ok = np.asarray(self.report.ok, bool)
-            self._observe_ready()
+        if self._ok is None:
+            self._ok = self._pull(self.report.ok)
         return self._ok
 
     def routed(self) -> np.ndarray:
         if self._routed is None:
-            self._routed = np.asarray(self.report.routed, bool)
-            self._observe_ready()
+            self._routed = self._pull(self.report.routed)
         return self._routed
 
 

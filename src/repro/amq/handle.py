@@ -6,6 +6,11 @@ its state, so the old buffers are dead — donation lets XLA update the table
 in place, the batch analogue of the paper's in-place CAS writes). A caller
 that kept a reference to an old ``handle.state`` finds it deleted after the
 next mutating op, on the CPU exactly as on a TPU.
+
+Each jitted op compiles under the name ``<backend>_<op>``
+(:func:`~repro.amq.adapters.program_name`), so a device trace names the
+program ``jit_cuckoo_query``; each op is also the host span
+``amq.handle.<op>`` (:mod:`repro.amq.spans`).
 """
 
 from __future__ import annotations
@@ -20,6 +25,8 @@ from ..core.hashing import normalize_keys
 from .adapters import (
     AMQAdapter,
     config_fingerprint,
+    named,
+    program_name,
     segmented_apply_ops,
 )
 from .protocol import (
@@ -33,6 +40,7 @@ from .protocol import (
     SnapshotMismatchError,
     load_factor as _load_factor,
 )
+from .spans import span
 
 
 def _check_snapshot_target(adapter: AMQAdapter, config: Any,
@@ -125,9 +133,33 @@ class FilterHandle:
                                     **static)
             if self.adapter.jit:
                 donate = (0,) if op != "query" else ()
-                raw = jax.jit(raw, donate_argnums=donate)
+                raw = jax.jit(named(raw, program_name(self.name, op)),
+                              donate_argnums=donate)
             self._jits[key] = raw
         return self._jits[key]
+
+    def compiled(self, op: str, *args, valid=None,
+                 **static) -> jax.stages.Compiled:
+        """The compiled program of ``op`` for these arguments.
+
+        ``args`` are what the op takes after the state (``keys``, and
+        ``ops`` for ``apply_ops``), as arrays or ``jax.ShapeDtypeStruct``;
+        ``static`` are the op's static options (``dedup_within_batch``).
+        The program is the one the op runs at these shapes, so its
+        ``as_text()`` names the instructions a device trace of the op shows.
+        Only for backends the handle jits (``adapter.jit``).
+
+        Example::
+
+            >>> keys = jax.ShapeDtypeStruct((1 << 20, 2), jnp.uint32)
+            >>> text = handle.compiled("query", keys).as_text()
+        """
+        if not self.adapter.jit:
+            raise NotImplementedError(
+                f"{self.name}: the handle does not compile this backend's "
+                "ops (adapter.jit is False)")
+        return self._fn(op, **static).lower(self.state, *args,
+                                            valid=valid).compile()
 
     def insert(self, keys, *, bulk: bool = False,
                dedup_within_batch: bool = False,
@@ -152,8 +184,10 @@ class FilterHandle:
                     "(capabilities.supports_bulk is False)")
             op = "insert_bulk"
         fn = self._fn(op, dedup_within_batch=dedup_within_batch)
-        self.state, report = fn(self.state, normalize_keys(keys),
-                                valid=valid)
+        with span("handle." + op) as s:
+            keys = normalize_keys(keys)
+            s.set_metadata(keys=keys.shape[0])
+            self.state, report = fn(self.state, keys, valid=valid)
         return report
 
     def query(self, keys, *, valid=None) -> QueryResult:
@@ -163,8 +197,10 @@ class FilterHandle:
 
             >>> hits = handle.query(keys).hits  # bool[n]
         """
-        _, result = self._fn("query")(self.state, normalize_keys(keys),
-                                      valid=valid)
+        with span("handle.query") as s:
+            keys = normalize_keys(keys)
+            s.set_metadata(keys=keys.shape[0])
+            _, result = self._fn("query")(self.state, keys, valid=valid)
         return result
 
     def delete(self, keys, *, valid=None) -> DeleteReport:
@@ -180,8 +216,11 @@ class FilterHandle:
             raise NotImplementedError(
                 f"{self.name}: append-only structure "
                 "(capabilities.supports_delete is False)")
-        self.state, report = self._fn("delete")(
-            self.state, normalize_keys(keys), valid=valid)
+        with span("handle.delete") as s:
+            keys = normalize_keys(keys)
+            s.set_metadata(keys=keys.shape[0])
+            self.state, report = self._fn("delete")(self.state, keys,
+                                                    valid=valid)
         return report
 
     def apply_ops(self, batch: OpBatch) -> MixedReport:
@@ -200,11 +239,12 @@ class FilterHandle:
             >>> bool(handle.apply_ops(batch).ok.all())   # doctest: +SKIP
             True
         """
-        if self.adapter.apply_ops is None:
-            return segmented_apply_ops(self, batch)
-        fn = self._fn("apply_ops")
-        self.state, report = fn(self.state, batch.keys, batch.ops,
-                                valid=batch.valid)
+        with span("handle.apply_ops", keys=batch.keys.shape[0]):
+            if self.adapter.apply_ops is None:
+                return segmented_apply_ops(self, batch)
+            fn = self._fn("apply_ops")
+            self.state, report = fn(self.state, batch.keys, batch.ops,
+                                    valid=batch.valid)
         return report
 
     def count(self) -> int:

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Iterable, Optional
 
-from .adapters import DEFAULT_ADAPTERS, AMQAdapter
+from .adapters import DEFAULT_ADAPTERS, AMQAdapter, program_name
 from .handle import FilterHandle
 
 
@@ -26,6 +26,11 @@ def _validate(adapter: AMQAdapter) -> None:
     that branch on a flag get the documented NotImplementedError — never a
     'NoneType is not callable' deep inside a jit cache."""
     caps = adapter.capabilities
+    if program_name(adapter.name, "").startswith("bench_"):
+        raise ValueError(
+            f"{adapter.name!r}: backend names may not start with 'bench' "
+            "(device traces count programs named jit_bench_* as the "
+            "benchmark's own, not the filter's)")
     if caps.supports_delete and not callable(adapter.delete):
         raise ValueError(
             f"{adapter.name!r}: supports_delete=True but no delete op")

@@ -34,12 +34,15 @@ dispatches. The service bridges the two (DESIGN.md §9, serving engine §11):
 * **Scatter**: every submission returns a :class:`Ticket` that knows which
   slots of which micro-batches carry its ops; ``result()`` gathers exactly
   those slots back into per-client order, however the ops were interleaved.
-  Tickets carry enqueue → dispatch → ready timestamps.
+  Tickets carry a per-service sequence number and enqueue → dispatch →
+  ready timestamps.
 * **Observability**: a :class:`~repro.amq.dispatch.ServiceMetrics` ledger
   (histogram-bucketed enqueue→dispatch / enqueue→ready latency, queue
   depth, padding waste, dispatch-size and trigger distributions, per-client
   admission outcomes, swap pauses) — read the legacy counters as
-  ``svc.stats["ops"]`` and the full SLO snapshot as ``svc.stats()``.
+  ``svc.stats["ops"]`` and the full SLO snapshot as ``svc.stats()``. The
+  same boundaries are host spans on the profiler's clock
+  (``amq.service.submit`` / ``dispatch`` / ``ready``, :mod:`repro.amq.spans`).
 * **Hot swap** (DESIGN.md §10): :meth:`FilterService.hot_swap` drains the
   pending stream onto the old backend, migrates its state onto a new
   handle via snapshot/restore (including exact resharding onto a new mesh
@@ -85,6 +88,7 @@ from .protocol import (
     OpBatch,
     normalize_ops,
 )
+from .spans import span
 
 _ADMISSION_POLICIES = ("block", "shed", "error")
 
@@ -97,6 +101,9 @@ class Ticket:
     the matching routed mask (sharded backends). Both force a flush of any
     still-pending part of the submission.
 
+    ``seq`` numbers the service's submissions from 0, in call order (the
+    ``ticket`` argument of the ``amq.service.*`` spans).
+
     Lifecycle timestamps (service-clock seconds): ``t_enqueue`` when the
     submission was accepted, ``t_dispatch`` when its last op left the
     pending queue, ``t_ready`` when its results were first gathered.
@@ -105,10 +112,11 @@ class Ticket:
     on its behalf).
     """
 
-    def __init__(self, service: "FilterService", n: int, *, client=None,
-                 shed: bool = False):
+    def __init__(self, service: "FilterService", n: int, seq: int, *,
+                 client=None, shed: bool = False):
         self._service = service
         self._n = n
+        self.seq = seq
         self.client = client
         self.shed = shed
         self.t_enqueue: float = service._clock()
@@ -266,6 +274,8 @@ class FilterService:
         self._ladder = shape_ladder(self.batch_size, self._align)
         self._queue = PendingStream()
         self._in_flight: List[Dispatch] = []
+        self._tickets = 0      # sequence number of the next Ticket
+        self._dispatches = 0   # sequence number of the next Dispatch
         self.metrics = ServiceMetrics()
         self.stats = _ServiceStats(self)
 
@@ -312,16 +322,26 @@ class FilterService:
         nothing is enqueued, no padded dispatch is forced, and no deadline
         starts ticking.
         """
-        keys = np.asarray(normalize_keys(keys, arg="keys"), np.uint32)
-        ops = np.asarray(normalize_ops(ops, keys.shape[0]), np.int32)
-        if ((ops == OP_DELETE).any()
-                and not self.handle.capabilities.supports_delete):
-            raise NotImplementedError(
-                f"{self.handle.name}: append-only backend cannot serve "
-                "deletes (capabilities.supports_delete is False)")
+        with span("service.submit") as sp:
+            keys = np.asarray(normalize_keys(keys, arg="keys"), np.uint32)
+            ops = np.asarray(normalize_ops(ops, keys.shape[0]), np.int32)
+            if ((ops == OP_DELETE).any()
+                    and not self.handle.capabilities.supports_delete):
+                raise NotImplementedError(
+                    f"{self.handle.name}: append-only backend cannot serve "
+                    "deletes (capabilities.supports_delete is False)")
+            seq = self._tickets
+            self._tickets += 1
+            sp.set_metadata(ticket=seq, ops=keys.shape[0])
+            return self._admit(keys, ops, seq, client)
+
+    def _admit(self, keys: np.ndarray, ops: np.ndarray, seq: int,
+               client) -> Ticket:
+        """Admission control (DESIGN.md §11), then the ops join the stream
+        as ticket ``seq``."""
         n = keys.shape[0]
         if n == 0:
-            return Ticket(self, 0, client=client)
+            return Ticket(self, 0, seq, client=client)
 
         # -- admission control (DESIGN.md §11) -------------------------------
         if self.max_pending is not None:
@@ -346,9 +366,9 @@ class FilterService:
                             f"pending queue full: {self._queue.pending} "
                             f"pending + {n} submitted exceeds {bound}")
                     self.metrics.observe_shed(n, client)
-                    return Ticket(self, n, client=client, shed=True)
+                    return Ticket(self, n, seq, client=client, shed=True)
 
-        ticket = Ticket(self, n, client=client)
+        ticket = Ticket(self, n, seq, client=client)
         self._queue.append(keys, ops, ticket.t_enqueue, ticket, client)
         self.stats["ops"] += n
         self.metrics.observe_enqueue(n, client, self._queue.pending)
@@ -482,38 +502,45 @@ class FilterService:
             self.flush()
 
     def _dispatch(self, m: int, kind: str = "full") -> None:
-        now = self._clock()
-        keys, ops, enqueued_at, claims = self._queue.take(m)
+        seq = self._dispatches
+        self._dispatches += 1
         shape = rung_for(m, self._ladder)
-        # Host-side padding: each channel crosses host->device once, at
-        # its final ladder shape (no device concatenates per dispatch).
-        batch = OpBatch.make_padded(keys, ops, shape)
-        report = self.handle.apply_ops(batch)  # async: not concretised here
-        dispatch = Dispatch(report, self.metrics, self._clock, enqueued_at)
-        self.stats["dispatches"] += 1
-        self.stats["padded"] += shape - m
-        self.metrics.observe_dispatch(m, shape, kind, now - enqueued_at)
+        with span("service.dispatch", dispatch=seq, live=m, shape=shape,
+                  kind=kind) as sp:
+            now = self._clock()
+            keys, ops, enqueued_at, claims = self._queue.take(m)
+            sp.set_metadata(first=claims[0][0].seq, last=claims[-1][0].seq)
+            # Host-side padding: each channel crosses host->device once, at
+            # its final ladder shape (no device concatenates per dispatch).
+            batch = OpBatch.make_padded(keys, ops, shape)
+            # Asynchronous: the report is not concretised here.
+            report = self.handle.apply_ops(batch)
+            dispatch = Dispatch(report, self.metrics, self._clock,
+                                enqueued_at, seq)
+            self.stats["dispatches"] += 1
+            self.stats["padded"] += shape - m
+            self.metrics.observe_dispatch(m, shape, kind, now - enqueued_at)
 
-        # Scatter the contiguous claim ranges back onto tickets (the
-        # tickets alone keep a dispatch alive past the in-flight window —
-        # see Ticket._parts).
-        slot = 0
-        for ticket, start, cnt in claims:
-            ticket._parts.append((dispatch,
-                                  np.arange(slot, slot + cnt),
-                                  np.arange(start, start + cnt)))
-            ticket._filled += cnt
-            if ticket._filled >= ticket._n:
-                ticket.t_dispatch = now
-            slot += cnt
+            # Scatter the contiguous claim ranges back onto tickets (the
+            # tickets alone keep a dispatch alive past the in-flight window —
+            # see Ticket._parts).
+            slot = 0
+            for ticket, start, cnt in claims:
+                ticket._parts.append((dispatch,
+                                      np.arange(slot, slot + cnt),
+                                      np.arange(start, start + cnt)))
+                ticket._filled += cnt
+                if ticket._filled >= ticket._n:
+                    ticket.t_dispatch = now
+                slot += cnt
 
-        # Slide the in-flight window: concretising the oldest batch is the
-        # double-buffering backstop (bounded device-result backlog) and
-        # what stamps enqueue→ready latencies promptly. With an unbounded
-        # window the service tracks nothing (tickets alone own dispatches,
-        # the pre-§11 behaviour).
-        if self.max_in_flight is not None:
-            self._in_flight.append(dispatch)
-            while len(self._in_flight) > self.max_in_flight:
-                self._in_flight.pop(0).ok()
-            self._in_flight = [d for d in self._in_flight if not d.done]
+            # Slide the in-flight window: concretising the oldest batch is the
+            # double-buffering backstop (bounded device-result backlog) and
+            # what stamps enqueue→ready latencies promptly. With an unbounded
+            # window the service tracks nothing (tickets alone own dispatches,
+            # the pre-§11 behaviour).
+            if self.max_in_flight is not None:
+                self._in_flight.append(dispatch)
+                while len(self._in_flight) > self.max_in_flight:
+                    self._in_flight.pop(0).ok()
+                self._in_flight = [d for d in self._in_flight if not d.done]

@@ -156,6 +156,7 @@ class CuckooConfig:
 # Key preparation (Alg. 1 lines 2-5).
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("hash")
 def prepare_keys(config: CuckooConfig, keys: jnp.ndarray):
     """keys uint32[n, 2] -> (base_tag, i1, i2), all uint32[n]."""
     hi, lo = hash_key(keys, config.hash_kind, config.seed)
@@ -779,8 +780,9 @@ def _insert_orient(
     # Free capacity of each edge's two endpoints, read from the packed
     # words of just the buckets this batch touches (SWAR zero-lane count) —
     # never a per-slot view of the whole table.
-    free1 = _bucket_free(config, state.table, i1s)
-    free2 = _bucket_free(config, state.table, i2s)
+    with jax.named_scope("orient"):
+        free1 = _bucket_free(config, state.table, i1s)
+        free2 = _bucket_free(config, state.table, i2s)
 
     # Edges whose candidate buckets are both already full can never be
     # placed by orientation (existing entries never move); dropping them
@@ -828,9 +830,10 @@ def _insert_orient(
     def sweep_cond(carry):
         return (~carry[1]) & (carry[2] < sweeps)
 
-    orient, _, _ = jax.lax.while_loop(
-        sweep_cond, sweep_body,
-        (orient0, jnp.zeros((), bool), jnp.zeros((), jnp.int32)))
+    with jax.named_scope("orient"):
+        orient, _, _ = jax.lax.while_loop(
+            sweep_cond, sweep_body,
+            (orient0, jnp.zeros((), bool), jnp.zeros((), jnp.int32)))
 
     # Conflict-free commit of the oriented edges, then a second chance on
     # the opposite bucket for the few keys an unconverged sweep left over.
@@ -853,8 +856,9 @@ def _insert_orient(
     # The round loop handles them regardless of the eviction policy: its
     # per-round claim pass is much cheaper at full batch width than the
     # frontier's gather tree, and the residue is a small tail.
-    state2, ok_res, res_stats = _insert_rounds(
-        config, CuckooState(table, count), keys, valid=pending)
+    with jax.named_scope("residue"):
+        state2, ok_res, res_stats = _insert_rounds(
+            config, CuckooState(table, count), keys, valid=pending)
 
     ok = placed | ok_res
     if dedup_within_batch:
@@ -937,6 +941,7 @@ def _bucket_free(config: CuckooConfig, table: jnp.ndarray,
     return jnp.sum(zeros, axis=-1, dtype=jnp.int32)
 
 
+@jax.named_scope("bulk_place")
 def _bulk_place_phase(config: CuckooConfig, table: jnp.ndarray,
                       bucket: jnp.ndarray, stored_tag: jnp.ndarray,
                       pend: jnp.ndarray):
@@ -1039,8 +1044,9 @@ def insert_bulk(
 
     # Residue: both candidate buckets full — hand the stragglers to the
     # eviction-capable round loop against the bulk-updated table.
-    state2, ok_res, res_stats = residue_fn(
-        config, CuckooState(table, count), keys, valid=pending)
+    with jax.named_scope("residue"):
+        state2, ok_res, res_stats = residue_fn(
+            config, CuckooState(table, count), keys, valid=pending)
 
     ok = placed | ok_res
     if dedup_within_batch:
@@ -1061,12 +1067,14 @@ def query(config: CuckooConfig, state: CuckooState, keys: jnp.ndarray) -> jnp.nd
     lay = config.layout
     pol = config.placement
     base_tag, i1, i2 = prepare_keys(config, keys)
-    t1, t2 = pol.query_match_tags(base_tag)
+    with jax.named_scope("hash"):
+        t1, t2 = pol.query_match_tags(base_tag)
     tags1 = L.bucket_tags(state.table, i1, lay)
     tags2 = L.bucket_tags(state.table, i2, lay)
-    hit1 = jnp.any(tags1 == t1[:, None], axis=-1)
-    hit2 = jnp.any(tags2 == t2[:, None], axis=-1)
-    return hit1 | hit2
+    with jax.named_scope("tag_match"):
+        hit1 = jnp.any(tags1 == t1[:, None], axis=-1)
+        hit2 = jnp.any(tags2 == t2[:, None], axis=-1)
+        return hit1 | hit2
 
 
 # ---------------------------------------------------------------------------
@@ -1085,7 +1093,8 @@ def delete(
     max_rounds = 2 * config.bucket_size + 2  # duplicate deleters serialise
 
     base_tag, i1, i2 = prepare_keys(config, keys)
-    t1, t2 = pol.query_match_tags(base_tag)
+    with jax.named_scope("hash"):
+        t1, t2 = pol.query_match_tags(base_tag)
 
     def round_fn(carry):
         table, count, pending, success, rnd = carry
@@ -1094,9 +1103,10 @@ def delete(
         tags1 = L.unpack_words(words1, lay.fp_bits)
         tags2 = L.unpack_words(words2, lay.fp_bits)
 
-        start = L.scan_start(base_tag, lay)
-        f1, s1 = L.first_true_circular(tags1 == t1[:, None], start)
-        f2, s2 = L.first_true_circular(tags2 == t2[:, None], start)
+        with jax.named_scope("tag_match"):
+            start = L.scan_start(base_tag, lay)
+            f1, s1 = L.first_true_circular(tags1 == t1[:, None], start)
+            f2, s2 = L.first_true_circular(tags2 == t2[:, None], start)
 
         found = f1 | f2
         bucket = jnp.where(f1, i1, i2)
@@ -1151,11 +1161,14 @@ def _count_matches(config: CuckooConfig, state: CuckooState,
     lay = config.layout
     pol = config.placement
     base_tag, i1, i2 = prepare_keys(config, keys)
-    t1, t2 = pol.query_match_tags(base_tag)
-    cnt1 = jnp.sum(L.bucket_tags(state.table, i1, lay) == t1[:, None],
-                   axis=-1, dtype=jnp.int32)
-    cnt2 = jnp.sum(L.bucket_tags(state.table, i2, lay) == t2[:, None],
-                   axis=-1, dtype=jnp.int32)
+    with jax.named_scope("hash"):
+        t1, t2 = pol.query_match_tags(base_tag)
+    # The bucket reads nest their own phases inside ``tag_match``.
+    with jax.named_scope("tag_match"):
+        cnt1 = jnp.sum(L.bucket_tags(state.table, i1, lay) == t1[:, None],
+                       axis=-1, dtype=jnp.int32)
+        cnt2 = jnp.sum(L.bucket_tags(state.table, i2, lay) == t2[:, None],
+                       axis=-1, dtype=jnp.int32)
     aliased = (i1 == i2) & (t1 == t2)
     return jnp.where(aliased, cnt1, cnt1 + cnt2)
 

@@ -9,6 +9,13 @@ The table is a flat ``uint32[num_buckets * words_per_bucket]`` array; a bucket
 is the contiguous word range ``[b * wpb, (b+1) * wpb)`` — bucket-major layout
 so one vector load covers a whole bucket (the TPU analogue of the paper's
 256-bit vectorized query loads).
+
+The bucket reads name their phases with ``jax.named_scope``, nested in the
+caller's: ``row_gather`` (the table gather), ``lane_select`` (picking
+words or lanes by index: the row's masked lane reductions, :func:`pick`)
+and ``tag_match`` (unpacking words into tags). Each compiled instruction
+carries its scope in its metadata, so a device trace of a program can be
+read phase by phase (PERF.md §3).
 """
 
 from __future__ import annotations
@@ -119,6 +126,7 @@ def swar_mask_to_bools(mask: jnp.ndarray, fp_bits: int) -> jnp.ndarray:
 # Pack / unpack and slot read-modify-write.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("tag_match")
 def unpack_words(words: jnp.ndarray, fp_bits: int) -> jnp.ndarray:
     """uint32[..., W] packed words -> uint32[..., W * tpw] tag values."""
     tpw = 32 // fp_bits
@@ -169,6 +177,7 @@ def gather_bucket_words(table: jnp.ndarray, bucket: jnp.ndarray, layout: BucketL
         tpu=functools.partial(gather_by_row, layout=layout))
 
 
+@jax.named_scope("row_gather")
 def gather_by_word(table: jnp.ndarray, bucket: jnp.ndarray,
                    layout: BucketLayout) -> jnp.ndarray:
     """One scalar gather per packed word of each bucket."""
@@ -187,16 +196,19 @@ def gather_by_row(table: jnp.ndarray, bucket: jnp.ndarray,
     wpb = layout.words_per_bucket
     per_row = _ROW_WORDS // wpb
     bucket = bucket.astype(jnp.int32)
-    rows = table.reshape(-1, _ROW_WORDS)[bucket // per_row]   # [..., 128]
-    first = ((bucket % per_row) * wpb)[..., None]
-    lane = jnp.arange(_ROW_WORDS, dtype=jnp.int32)
-    # One masked lane-reduction per word keeps every temporary 128 lanes
-    # wide (a [..., per_row, wpb] view would pad wpb up to 128 lanes).
-    return jnp.stack([jnp.sum(jnp.where(lane == first + j, rows, _U32(0)),
-                              axis=-1, dtype=jnp.uint32)
-                      for j in range(wpb)], axis=-1)
+    with jax.named_scope("row_gather"):
+        rows = table.reshape(-1, _ROW_WORDS)[bucket // per_row]  # [..., 128]
+    with jax.named_scope("lane_select"):
+        first = ((bucket % per_row) * wpb)[..., None]
+        lane = jnp.arange(_ROW_WORDS, dtype=jnp.int32)
+        # One masked lane-reduction per word keeps every temporary 128 lanes
+        # wide (a [..., per_row, wpb] view would pad wpb up to 128 lanes).
+        return jnp.stack([jnp.sum(jnp.where(lane == first + j, rows, _U32(0)),
+                                  axis=-1, dtype=jnp.uint32)
+                          for j in range(wpb)], axis=-1)
 
 
+@jax.named_scope("lane_select")
 def pick(values: jnp.ndarray, index: jnp.ndarray, axis: int) -> jnp.ndarray:
     """``values`` indexed by ``index`` along ``axis`` (axis removed).
 
